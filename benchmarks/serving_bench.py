@@ -41,13 +41,7 @@ from repro.serving import (Engine, Request, WorkloadSpec, poisson_trace,
                            run_open_loop, MetricsRecorder, find_saturation,
                            FinishReason, ChaosEvent, FaultInjector,
                            TickClock)
-
-
-def _compile_counter():
-    from jax._src import test_util as jtu
-    if hasattr(jtu, "count_jit_compilation_cache_miss"):
-        return jtu.count_jit_compilation_cache_miss()
-    return jtu.count_jit_and_pmap_lowerings()
+from repro.testing import count_compiles
 
 
 def _pct(xs, q):
@@ -201,12 +195,12 @@ def _open_loop_suite(emit, params, cfg, smoke):
                         shared_prefix_ratio=0.5, shared_prefix_len=12,
                         vocab=cfg.vocab_size, seed=0)
     rec = MetricsRecorder()
-    with _compile_counter() as n_compiles:
+    with count_compiles() as n_compiles:
         handles, _ = run_open_loop(eng, poisson_trace(spec), rec)
     post = eng.warmup_report()["post_warmup_compiles"]
     summ = rec.summary(sla_ttft_ms=sla_ttft_ms, sla_tpot_ms=sla_tpot_ms)
     good = summ["goodput"]
-    gates = {"zero_compiles": n_compiles[0] == 0 and post == 0,
+    gates = {"zero_compiles": n_compiles() == 0 and post == 0,
              "all_finished": summ["n_finished"] == summ["n_requests"],
              "goodput_rows": summ["n_requests"] > 0
              and good["goodput_rps"] >= 0.0}
@@ -231,7 +225,7 @@ def _open_loop_suite(emit, params, cfg, smoke):
          f"goodput_rps={good['goodput_rps']:.2f};"
          f"goodput_tok_s={good['goodput_tok_s']:.2f};"
          f"post_warmup_compiles={post};"
-         f"traffic_compiles={n_compiles[0]};"
+         f"traffic_compiles={n_compiles()};"
          f"gate={'pass' if all(gates.values()) else 'FAIL'}")
 
     # saturation sweep: same engine, ascending offered load, find the last
@@ -259,7 +253,7 @@ def _open_loop_suite(emit, params, cfg, smoke):
     if failed:
         raise RuntimeError(
             f"open-loop serving gates failed: {failed} "
-            f"(traffic_compiles={n_compiles[0]}, post_warmup={post}, "
+            f"(traffic_compiles={n_compiles()}, post_warmup={post}, "
             f"summary={summ})")
 
 

@@ -1,7 +1,12 @@
 """Bit-packing of integer quantization codes along the last (channel) axis.
 
-Codes are packed little-endian-within-byte: code ``i`` of a byte occupies bits
-``[i*b, (i+1)*b)``.  Supported code widths are 1, 2, 4 and 8 bits (8 is the
+Codes are packed *strided*: with ``cpb = 8 // b`` codes per byte and
+``Wb = N / cpb`` bytes, byte ``j`` holds channels ``j, j + Wb, j + 2*Wb, ...``
+and channel ``j + i*Wb`` occupies bits ``[i*b, (i+1)*b)``.  Unpacking is then
+``cpb`` shift-and-mask passes over the whole byte row, concatenated along the
+channel axis — no lane interleave, which a Mosaic (TPU) kernel cannot lower.
+SKVQ permutes channels anyway (:mod:`repro.core.reorder`), so this order is
+internal to the cache.  Supported code widths are 1, 2, 4 and 8 bits (8 is the
 identity).  Mixed widths (the paper's "1.5-bit" values) are handled one level
 up (see :mod:`repro.core.quant`) by packing two planes — one per width — so the
 kernels never see fractional widths.
@@ -40,9 +45,9 @@ def pack(codes: jnp.ndarray, bits: int) -> jnp.ndarray:
         return codes.astype(jnp.uint8)
     *lead, n = codes.shape
     out_w = packed_width(n, bits)
-    c = codes.astype(jnp.uint8).reshape(*lead, out_w, cpb)
-    shifts = (jnp.arange(cpb, dtype=jnp.uint8) * bits).astype(jnp.uint8)
-    return (c << shifts).sum(axis=-1, dtype=jnp.uint8)
+    c = codes.astype(jnp.uint8).reshape(*lead, cpb, out_w)
+    shifts = (jnp.arange(cpb, dtype=jnp.uint8) * bits)[:, None]
+    return (c << shifts).sum(axis=-2, dtype=jnp.uint8)
 
 
 def unpack_u8(packed: jnp.ndarray, bits: int) -> jnp.ndarray:
@@ -52,10 +57,10 @@ def unpack_u8(packed: jnp.ndarray, bits: int) -> jnp.ndarray:
     if bits == 8:
         return packed
     *lead, w = packed.shape
-    shifts = jnp.arange(cpb, dtype=jnp.uint8) * bits
+    shifts = (jnp.arange(cpb, dtype=jnp.uint8) * bits)[:, None]
     mask = jnp.uint8((1 << bits) - 1)
-    codes = (packed[..., None] >> shifts) & mask
-    return codes.reshape(*lead, w * cpb)
+    codes = (packed[..., None, :] >> shifts) & mask
+    return codes.reshape(*lead, cpb * w)
 
 
 def unpack(packed: jnp.ndarray, bits: int) -> jnp.ndarray:

@@ -84,8 +84,8 @@ def pipeline_forward(block_fn: Callable, params_stacked, x, *, mesh,
 
     # params: stage s gets layers [s*per_stage, (s+1)*per_stage)
     in_specs = (jax.tree.map(lambda _: P(axis), params_stacked), P())
-    from .sharding import shard_map_compat
-    f = shard_map_compat(stage_fn, mesh, in_specs, P(), {axis})
+    f = jax.shard_map(stage_fn, mesh=mesh, in_specs=in_specs, out_specs=P(),
+                      axis_names={axis}, check_vma=False)
     stage_view = jax.tree.map(
         lambda a: a.reshape(n_stages, per_stage, *a.shape[1:]), params_stacked)
     # shard_map with P(axis) expects the leading dim == n_stages blocks

@@ -166,25 +166,6 @@ _PARAM_RULES = (
 )
 
 
-def shard_map_compat(f, mesh: Mesh, in_specs, out_specs, axis_names):
-    """Partial shard_map across jax versions.
-
-    jax >= 0.6 spells it ``jax.shard_map(..., axis_names=..., check_vma=)``;
-    0.4.x spells the same thing ``jax.experimental.shard_map.shard_map(...,
-    auto=<complement of axis_names>, check_rep=False)``.  ``axis_names`` is
-    the set of mesh axes handled manually inside ``f``; the rest stay under
-    GSPMD.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, axis_names=set(axis_names),
-                             check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    auto = frozenset(mesh.axis_names) - set(axis_names)
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False, auto=auto)
-
-
 def param_partition_specs(params, mesh: Mesh):
     """PartitionSpec pytree for a param tree, by key-name rules."""
 

@@ -1,14 +1,8 @@
-"""Shared Pallas-TPU compat layer for the fused kernels (DESIGN.md §4).
+"""Interpret-mode policy for the fused Pallas kernels (DESIGN.md §4).
 
-One module-level home for the pieces ``decode_attn.py`` and ``kv_quant.py``
-used to re-derive locally:
-
-* ``pltpu`` — the ``jax.experimental.pallas.tpu`` module, imported once;
-* ``CompilerParams`` — jax renamed ``TPUCompilerParams`` ->
-  ``CompilerParams`` across releases; this is whichever the installed jax
-  provides (None if neither exists, in which case callers skip the param);
-* :func:`resolve_interpret` — the single policy for whether a kernel runs
-  compiled or in the Pallas interpreter.
+:func:`resolve_interpret` is the single policy for whether a kernel runs
+compiled or in the Pallas interpreter; ``decode_attn.py`` and
+``kv_quant.py`` both resolve through it.
 
 Interpret-mode resolution (most-specific wins):
 
@@ -30,13 +24,8 @@ import os
 from typing import Optional
 
 import jax
-import jax.experimental.pallas.tpu as pltpu
 
 ENV_VAR = "REPRO_PALLAS_INTERPRET"
-
-# jax renamed TPUCompilerParams -> CompilerParams across releases
-CompilerParams = getattr(pltpu, "CompilerParams",
-                         getattr(pltpu, "TPUCompilerParams", None))
 
 _TRUE = {"1", "true", "yes", "on"}
 _FALSE = {"0", "false", "no", "off"}

@@ -30,7 +30,7 @@ grid axis so the accumulator scratch persists across KV tiles):
     q         (B, Hkv, Gq, D)      Gq = query heads per kv head (GQA)
     k planes  (B, Hkv, S, W_b)     packed uint8 + (B, Hkv, S, G) metadata
     v planes  likewise
-    mask      (B, S, 1) f32        1.0 for attendable tokens (validity ∧ local
+    mask      (B, S) f32           1.0 for attendable tokens (validity ∧ local
                                    window — computed by the wrapper).  Per
                                    batch slot: ragged serving batches place
                                    each row's packed frontier independently.
@@ -47,22 +47,25 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 import jax.experimental.pallas as pl
+import jax.experimental.pallas.tpu as pltpu
 
 from ..core.quant import plane_layout
 from ..core.policy import QuantPolicy
-from ._compat import CompilerParams, pltpu, resolve_interpret
-from .kv_quant import _decode_meta
+from ._compat import resolve_interpret
+from .kv_quant import _decode_meta, _expand_groups
 
 BLOCK_S = 256
 _NEG = -1e30
 
 
 def _unpack_block(packed, bits):
-    """(T, Wb) uint8 -> (T, Wb * 8//bits) uint8 codes."""
-    t, wb = packed.shape
-    cpb = 8 // bits
-    parts = [(packed >> (i * bits)) & ((1 << bits) - 1) for i in range(cpb)]
-    return jnp.stack(parts, axis=-1).reshape(t, wb * cpb)
+    """(T, Wb) uint8 -> (T, Wb * 8//bits) int32 codes in channel order: one
+    shift-and-mask pass per code slot of the strided ``core.packing``
+    layout, concatenated along lanes (uint8 widens through int32 — Mosaic
+    has no direct uint8 <-> float cast)."""
+    p = packed.astype(jnp.int32)
+    return jnp.concatenate([(p >> (i * bits)) & ((1 << bits) - 1)
+                            for i in range(8 // bits)], axis=-1)
 
 
 def _dequant_tile(refs, off, layout, fp8_meta):
@@ -72,10 +75,7 @@ def _dequant_tile(refs, off, layout, fp8_meta):
         codes = _unpack_block(refs[off + 3 * pi][0, 0], bits).astype(jnp.float32)
         h = _decode_meta(refs[off + 3 * pi + 1][0, 0], fp8_meta)   # (BS, G)
         lo = _decode_meta(refs[off + 3 * pi + 2][0, 0], fp8_meta)
-        t = codes.shape[0]
-        g = width // gs
-        xg = codes.reshape(t, g, gs) * h[..., None] + lo[..., None]
-        parts.append(xg.reshape(t, width))
+        parts.append(codes * _expand_groups(h, gs) + _expand_groups(lo, gs))
     return jnp.concatenate(parts, axis=-1) if len(parts) > 1 else parts[0]
 
 
@@ -108,24 +108,26 @@ def _kernel(bnd_ref, q_ref, mask_ref, *refs, layout_k, layout_v, fp8_meta,
         q = q_ref[0, 0].astype(jnp.float32) * scale          # (Gq, D)
         k = _dequant_tile(k_refs, 0, layout_k, fp8_meta)      # (BS, D)
         v = _dequant_tile(v_refs, 0, layout_v, fp8_meta)      # (BS, D)
-        mask = mask_ref[...][0, :, 0]                         # (BS,) this slot
+        mask = mask_ref[0, 0]                                 # (1, BS) this slot
 
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)  # (Gq, BS)
+        s = jax.lax.dot_general(                              # (Gq, BS) = q k^T
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
         if softcap > 0:
             s = softcap * jnp.tanh(s / softcap)
-        s = jnp.where(mask[None, :] > 0, s, _NEG)
+        s = jnp.where(mask > 0, s, _NEG)
 
         m_prev = m_sc[...]                                    # (Gq, 1)
-        m_cur = jnp.maximum(m_prev[:, 0], s.max(axis=-1))     # (Gq,)
+        m_cur = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
         # multiply by the mask so a partially-masked tile contributes exactly
         # zero weight on its dead lanes instead of exp(0)=1 per lane when
         # m_cur is still _NEG.
-        p = jnp.exp(s - m_cur[:, None]) * mask[None, :]
-        alpha = jnp.exp(m_prev[:, 0] - m_cur)                 # rescale old acc
-        l_sc[...] = (l_sc[...][:, 0] * alpha + p.sum(axis=-1))[:, None]
-        acc[...] = acc[...] * alpha[:, None] + jnp.dot(
+        p = jnp.exp(s - m_cur) * mask
+        alpha = jnp.exp(m_prev - m_cur)                       # rescale old acc
+        l_sc[...] = l_sc[...] * alpha + p.sum(axis=-1, keepdims=True)
+        acc[...] = acc[...] * alpha + jnp.dot(
             p, v, preferred_element_type=jnp.float32)
-        m_sc[...] = m_cur[:, None]
+        m_sc[...] = m_cur
 
     @pl.when(sblk == n_sblocks - 1)
     def _finish():
@@ -224,7 +226,7 @@ def decode_attn_pallas(q: jnp.ndarray, k_qt: dict, v_qt: dict,
             return (bh // hkv, bh % hkv, 0, 0)
 
         def _mask_map(bh, s, bnd, tbl):
-            return (bh // hkv, _blk(bh, s, bnd), 0)
+            return (bh // hkv, _blk(bh, s, bnd), 0, 0)
 
         def _plane_map(bh, s, bnd, tbl):
             return (tbl[bh // hkv, _blk(bh, s, bnd)], bh % hkv, 0, 0)
@@ -233,7 +235,7 @@ def decode_attn_pallas(q: jnp.ndarray, k_qt: dict, v_qt: dict,
             return (bh // hkv, bh % hkv, 0, 0)
 
         def _mask_map(bh, s, bnd):
-            return (bh // hkv, _blk(bh, s, bnd), 0)
+            return (bh // hkv, _blk(bh, s, bnd), 0, 0)
 
         def _plane_map(bh, s, bnd):
             return (bh // hkv, bh % hkv, _blk(bh, s, bnd), 0)
@@ -241,10 +243,12 @@ def decode_attn_pallas(q: jnp.ndarray, k_qt: dict, v_qt: dict,
     mask = jnp.asarray(mask, jnp.float32)
     if mask.ndim == 1:
         mask = jnp.broadcast_to(mask[None], (b, s_len))
-    ins = [q, mask.reshape(b, s_len, 1)]
+    # one lane-dense (1, block_s) mask row per (slot, block): the kernel
+    # broadcasts it over the query rows without a sublane->lane relayout
+    ins = [q, mask.reshape(b, n_sblocks, 1, block_s)]
     in_specs = [
         pl.BlockSpec((1, 1, gq, d), _head_map),
-        pl.BlockSpec((1, block_s, 1), _mask_map),
+        pl.BlockSpec((1, 1, 1, block_s), _mask_map),
     ]
     for qt, layout in ((k_qt, layout_k), (v_qt, layout_v)):
         for name, _ in zip(("hi", "lo"), layout):
@@ -273,9 +277,6 @@ def decode_attn_pallas(q: jnp.ndarray, k_qt: dict, v_qt: dict,
         out_specs=out_specs,
         scratch_shapes=scratch,
     )
-    extra = ({} if CompilerParams is None else
-             {"compiler_params": CompilerParams(
-                 dimension_semantics=("parallel", "arbitrary"))})
     kern = functools.partial(_kernel, layout_k=layout_k, layout_v=layout_v,
                              fp8_meta=policy.fp8_meta, scale=scale,
                              softcap=softcap, hkv=hkv, n_sblocks=grid_s)
@@ -296,6 +297,7 @@ def decode_attn_pallas(q: jnp.ndarray, k_qt: dict, v_qt: dict,
         grid_spec=grid_spec,
         out_shape=out_shape,
         interpret=interpret,
-        **extra,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
     )(*scalars, *ins)
     return num, m[..., 0:1], l[..., 0:1]

@@ -18,8 +18,8 @@ import jax
 import jax.numpy as jnp
 import jax.experimental.pallas as pl
 
+from ..core.fp8 import decode_fp8, encode_fp8, quantize_meta
 from ..core.quant import plane_layout
-from ..core.policy import QuantPolicy
 from ._compat import resolve_interpret
 
 BLOCK_T = 128
@@ -27,49 +27,65 @@ _EPS = 1e-8
 
 
 def _pack_block(codes, bits):
-    """codes: (T, W) uint8 values < 2**bits -> (T, W*bits//8) uint8."""
-    t, w = codes.shape
+    """codes: (T, W) int32 values < 2**bits -> (T, W*bits//8) uint8 in the
+    strided layout of ``core.packing`` (byte j holds channels j, j+Wb, ...):
+    lane slices OR-ed together, so no lane interleave reaches Mosaic."""
     cpb = 8 // bits
-    c = codes.reshape(t, w // cpb, cpb)
-    out = jnp.zeros((t, w // cpb), jnp.uint8)
-    for i in range(cpb):
-        out = out | (c[:, :, i] << (i * bits)).astype(jnp.uint8)
-    return out
+    wb = codes.shape[1] // cpb
+    out = codes[:, :wb]
+    for i in range(1, cpb):
+        out = out | (codes[:, i * wb:(i + 1) * wb] << (i * bits))
+    return out.astype(jnp.uint8)
+
+
+def _expand_groups(m, gs):
+    """(T, G) per-group values -> (T, G*gs) per-channel values (groups are
+    contiguous channel runs), built from lane broadcasts rather than a
+    (T, G, gs) reshape, which Mosaic cannot lower."""
+    t, g = m.shape
+    return jnp.concatenate(
+        [jnp.broadcast_to(m[:, i:i + 1], (t, gs)) for i in range(g)], axis=-1)
+
+
+def _group_reduce(x, gs, fn):
+    """(T, G*gs) -> (T, G): ``fn`` over each contiguous group of ``gs`` lanes."""
+    return jnp.concatenate([fn(x[:, i:i + gs], axis=-1, keepdims=True)
+                            for i in range(0, x.shape[1], gs)], axis=-1)
 
 
 def _encode_meta(x, fp8_meta):
+    """Storage bits of metadata already rounded by ``quantize_meta``."""
     if fp8_meta:
-        return jax.lax.bitcast_convert_type(x.astype(jnp.float8_e4m3fn), jnp.uint8)
+        return encode_fp8(x)
     return x.astype(jnp.float16)
 
 
 def _decode_meta(x, fp8_meta):
     if fp8_meta:
-        return jax.lax.bitcast_convert_type(x, jnp.float8_e4m3fn).astype(jnp.float32)
+        return decode_fp8(x)
     return x.astype(jnp.float32)
 
 
 def _kernel(x_ref, alpha_ref, *out_refs, layout, fp8_meta):
     x = x_ref[...].astype(jnp.float32)          # (BT, D)
-    n_planes = len(layout)
     g_off = 0
     for pi, (start, width, bits, gs) in enumerate(layout):
         xp = x[:, start:start + width]
-        t = xp.shape[0]
         g = width // gs
-        xg = xp.reshape(t, g, gs)
-        lo = xg.min(axis=-1)
-        hi = xg.max(axis=-1)
+        lo = _group_reduce(xp, gs, jnp.min)    # (BT, G)
+        hi = _group_reduce(xp, gs, jnp.max)
         a = alpha_ref[:, g_off:g_off + g]      # (1, G) shared or (BT, G) rows
         lo = lo * a
         hi = hi * a
         h = jnp.maximum((hi - lo) / (2 ** bits - 1), _EPS)
-        h = _decode_meta(_encode_meta(h, fp8_meta), fp8_meta)
-        lo = _decode_meta(_encode_meta(lo, fp8_meta), fp8_meta)
-        q = jnp.clip(jnp.round((xg - lo[..., None]) / h[..., None]),
-                     0, 2 ** bits - 1).astype(jnp.uint8)
+        h = quantize_meta(h, fp8_meta)
+        lo = quantize_meta(lo, fp8_meta)
+        # f32 -> int32 -> uint8: Mosaic has no direct float <-> uint8 cast
+        q = jnp.clip(jnp.round((xp - _expand_groups(lo, gs)) /
+                               _expand_groups(h, gs)),
+                     0, 2 ** bits - 1).astype(jnp.int32)
         codes_ref, scale_ref, zero_ref = (out_refs[3 * pi + j] for j in range(3))
-        codes_ref[...] = _pack_block(q.reshape(t, width), bits)
+        codes_ref[...] = _pack_block(q, bits)
         scale_ref[...] = _encode_meta(h, fp8_meta)
         zero_ref[...] = _encode_meta(lo, fp8_meta)
         g_off += g

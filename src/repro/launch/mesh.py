@@ -6,18 +6,11 @@ Defined as FUNCTIONS so importing this module never touches jax device state
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.5 exposes explicit axis types; older releases default to Auto
-    from jax.sharding import AxisType
-except (ImportError, AttributeError):  # pragma: no cover - version dependent
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def make_mesh(shape, axes):
-    """jax.make_mesh with Auto axis types when the installed jax supports
-    them (the kwarg does not exist on jax 0.4.x; Auto is its only behavior)."""
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
+    """jax.make_mesh with every axis Auto (sharding propagated by GSPMD)."""
     return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(shape))
 
 

@@ -61,6 +61,7 @@ from ..models import transformer as T
 from ..serving import (Engine, Request, WorkloadSpec, poisson_trace,
                        run_open_loop, MetricsRecorder,
                        ChaosSpec, chaos_trace, FaultInjector)
+from .compile_cache import enable_compile_cache
 
 
 def _pct(xs, q):
@@ -145,6 +146,18 @@ def _degradation_summary(eng, inj=None):
     except RuntimeError as e:
         print(f"FAIL: invariant audit: {e}", file=sys.stderr)
         raise SystemExit(1)
+
+
+def pool_tiled_max_len(max_len, schedule, block_tokens):
+    """Round ``max_len`` up until every quantized band's packed region
+    (``max_len - n_sink - window``) tiles into whole pool blocks
+    (DESIGN.md §9)."""
+    for _ in range(block_tokens):
+        if all(p.is_fp16 or (max_len - p.n_sink - p.window) % block_tokens == 0
+               for p in schedule.distinct()):
+            break
+        max_len += 1
+    return max_len
 
 
 def _open_loop(eng, args, cfg, n_req, max_len, inj=None):
@@ -311,6 +324,7 @@ def main(argv=None):
     ap.add_argument("--chaos-seed", type=int, default=0,
                     help="chaos trace seed (same seed, same fault ticks)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = configs.get_smoke(args.arch) if args.smoke else configs.get(args.arch)
     # the fp16 baseline stores every token raw: window/sink buffers would
@@ -361,14 +375,7 @@ def main(argv=None):
                + args.steps_per_sync)
     pooled = args.pool_blocks or args.pool_memory_mb
     if pooled:
-        # round max_len up so every quantized band's packed region
-        # (max_len - n_sink - window) tiles into whole pool blocks
-        bt = args.pool_block_tokens
-        for _ in range(bt):
-            if all(p.is_fp16 or (max_len - p.n_sink - p.window) % bt == 0
-                   for p in schedule.distinct()):
-                break
-            max_len += 1
+        max_len = pool_tiled_max_len(max_len, schedule, args.pool_block_tokens)
     inj = _chaos_injector(args)
     eng = Engine(params, cfg, schedule, batch_slots=args.batch,
                  max_len=max_len, backend=args.backend,

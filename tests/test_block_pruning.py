@@ -24,6 +24,7 @@ from repro.models import backends as B
 from repro.models.attention import decode_attention_skvq
 from repro.kernels.decode_attn import decode_attn_pallas
 from repro.kernels.ops import decode_block_report
+from repro.testing import count_compiles
 
 CFG = ArchConfig(name="t", family="dense", n_layers=2, d_model=64, n_heads=4,
                  n_kv_heads=2, head_dim=32, d_ff=32, vocab_size=64)
@@ -128,10 +129,6 @@ def test_parity_under_jit_traced_lengths(rng):
     """The serving path: lengths are traced, the grid stays capacity-sized,
     and pruning rides on the remap + skip — same numbers as eager, and
     growing lengths never recompile (the bounds are traced too)."""
-    from jax._src import test_util as jtu
-    counter = (jtu.count_jit_compilation_cache_miss
-               if hasattr(jtu, "count_jit_compilation_cache_miss")
-               else jtu.count_jit_and_pmap_lowerings)
     cache = _ragged_cache(rng, [20, 60])
     q = _q(rng, 2)
 
@@ -143,12 +140,12 @@ def test_parity_under_jit_traced_lengths(rng):
         np.asarray(attend(q, cache)),
         np.asarray(PAL.attend(q, cache, CFG, POL, dtype=jnp.float32)),
         atol=1e-6, rtol=1e-6)
-    with counter() as n_compiles:
+    with count_compiles() as n_compiles:
         for lens in ([21, 61], [40, 96], [12, 13]):
             out = attend(q, dict(cache, length=jnp.asarray(lens, jnp.int32)))
             out.block_until_ready()
-    assert n_compiles[0] == 0, (
-        f"block pruning recompiled {n_compiles[0]}x as slot lengths moved")
+    assert n_compiles() == 0, (
+        f"block pruning recompiled {n_compiles()}x as slot lengths moved")
 
 
 # ------------------------------------------------- kernel-level bitwise gate

@@ -42,3 +42,14 @@ def test_roundtrip_property(bits, lead, blocks, seed):
 def test_bad_bits():
     with pytest.raises(ValueError):
         pack(jnp.zeros((4, 8), jnp.uint8), 3)
+
+
+@pytest.mark.parametrize("bits", [1, 2, 4])
+def test_strided_byte_layout(bits, rng):
+    # byte j holds channels j, j + Wb, j + 2*Wb, ... (i-th in bits [i*b, (i+1)*b))
+    # — the layout the Pallas kernels unpack with shifts and a lane concat
+    cpb = codes_per_byte(bits)
+    c = rng.integers(0, 2 ** bits, size=(5, 64))
+    wb = 64 // cpb
+    want = sum(c[:, i * wb:(i + 1) * wb] << (i * bits) for i in range(cpb))
+    np.testing.assert_array_equal(np.asarray(pack(jnp.asarray(c), bits)), want)

@@ -26,6 +26,7 @@ from repro.core.policy import (QuantPolicy, PolicySchedule, SchedulePreset,
 from repro.models.config import ArchConfig
 from repro.models import transformer as T
 from repro.serving import Engine, Request
+from repro.testing import count_compiles
 
 CFG = ArchConfig(name="t", family="dense", n_layers=4, d_model=64, n_heads=4,
                  n_kv_heads=2, head_dim=32, d_ff=32, vocab_size=64)
@@ -270,13 +271,6 @@ def test_backend_parity_under_mixed_schedule(params, rng):
 
 # --------------------------------------------- (c) no-extra-compiles static
 
-def _compile_counter():
-    from jax._src import test_util as jtu
-    if hasattr(jtu, "count_jit_compilation_cache_miss"):
-        return jtu.count_jit_compilation_cache_miss()
-    return jtu.count_jit_and_pmap_lowerings()
-
-
 def test_two_policy_schedule_compiles_once(params, rng):
     """A schedule with 2 distinct policies compiles exactly ONE decode
     executable — bands live inside the jitted step, and repeated steps at
@@ -288,10 +282,10 @@ def test_two_policy_schedule_compiles_once(params, rng):
     fn = jax.jit(lambda p, t, c: T.decode_step(p, CFG, t, c, sched,
                                                backend="reference"))
     tok = jnp.zeros((2, 1), jnp.int32)
-    with _compile_counter() as n:
+    with count_compiles() as n:
         _, caches = fn(params, tok, caches)
-    assert n[0] == 1                      # warmup: exactly one executable
-    with _compile_counter() as n:
+    assert n() == 1                      # warmup: exactly one executable
+    with count_compiles() as n:
         for _ in range(3):                # lengths advance -> traced, cached
             _, caches = fn(params, tok, caches)
-    assert n[0] == 0, f"schedule decode recompiled {n[0]}x"
+    assert n() == 0, f"schedule decode recompiled {n()}x"

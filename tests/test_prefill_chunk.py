@@ -23,6 +23,7 @@ from repro.core import kv_cache as kvc
 from repro.models.config import ArchConfig
 from repro.models import transformer as T
 from repro.serving import Engine, Request, default_chunk_buckets
+from repro.testing import count_compiles
 
 CFG = ArchConfig(name="t", family="dense", n_layers=2, d_model=64, n_heads=4,
                  n_kv_heads=2, head_dim=32, d_ff=32, vocab_size=64)
@@ -147,13 +148,6 @@ def test_chunked_engine_streams_bitmatch_whole_prompt(params, rng, backend):
 
 # ----------------------------------------------- (c) bounded compile shapes
 
-def _compile_counter():
-    from jax._src import test_util as jtu
-    if hasattr(jtu, "count_jit_compilation_cache_miss"):
-        return jtu.count_jit_compilation_cache_miss()
-    return jtu.count_jit_and_pmap_lowerings()
-
-
 def test_ragged_traffic_bounded_prefill_compiles(params, rng):
     """>= 6 distinct prompt lengths compile <= len(chunk_buckets) prefill
     executables, and once the buckets are warm, arbitrarily new prompt
@@ -167,12 +161,12 @@ def test_ragged_traffic_bounded_prefill_compiles(params, rng):
     assert set(eng.prefill_shapes) <= set(eng.chunk_buckets)
 
     # six MORE distinct, previously-unseen lengths: everything is warm
-    with _compile_counter() as n_compiles:
+    with count_compiles() as n_compiles:
         wave2 = [eng.submit(Request(prompt=_prompt(rng, n), max_new=2))
                  for n in (6, 11, 18, 25, 30, 38)]
         eng.run(wave2)
-    assert n_compiles[0] == 0, (
-        f"chunked prefill recompiled {n_compiles[0]}x on new prompt lengths")
+    assert n_compiles() == 0, (
+        f"chunked prefill recompiled {n_compiles()}x on new prompt lengths")
     assert all(h.finished for h in wave2)
 
     # contrast: whole-prompt admission compiles per new length
@@ -180,10 +174,10 @@ def test_ragged_traffic_bounded_prefill_compiles(params, rng):
                    steps_per_sync=4)
     eng_warm = [whole.submit(Request(prompt=_prompt(rng, 9), max_new=2))]
     whole.run(eng_warm)
-    with _compile_counter() as n_compiles:
+    with count_compiles() as n_compiles:
         h = whole.submit(Request(prompt=_prompt(rng, 10), max_new=2))
         whole.run([h])
-    assert n_compiles[0] > 0
+    assert n_compiles() > 0
 
 
 def test_default_chunk_buckets_ladder():

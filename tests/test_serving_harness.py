@@ -32,6 +32,7 @@ from repro.serving import (Engine, Request, WorkloadSpec, poisson_trace,
                            run_open_loop, HostLoop, TokenDelivery,
                            MetricsRecorder, RequestRecord, percentiles,
                            goodput, find_saturation)
+from repro.testing import count_compiles
 
 CFG = ArchConfig(name="t", family="dense", n_layers=2, d_model=64, n_heads=4,
                  n_kv_heads=2, head_dim=32, d_ff=32, vocab_size=64)
@@ -49,13 +50,6 @@ def params():
 
 def _prompt(rng, n):
     return np.asarray(rng.integers(0, CFG.vocab_size, (n,)), np.int32)
-
-
-def _compile_counter():
-    from jax._src import test_util as jtu
-    if hasattr(jtu, "count_jit_compilation_cache_miss"):
-        return jtu.count_jit_compilation_cache_miss()
-    return jtu.count_jit_and_pmap_lowerings()
 
 
 # ------------------------------------------------ (a) loadgen determinism
@@ -129,11 +123,11 @@ def test_zero_compiles_after_warmup(params, rng):
                         prompt_lens=(9, 14, 21), max_news=(2, 3),
                         shared_prefix_ratio=0.5, shared_prefix_len=5,
                         vocab=CFG.vocab_size, seed=3)
-    with _compile_counter() as n_compiles:
+    with count_compiles() as n_compiles:
         handles, _ = run_open_loop(eng, poisson_trace(spec),
                                    time_scale=0.01)
-    assert n_compiles[0] == 0, (
-        f"{n_compiles[0]} XLA compiles leaked past warmup "
+    assert n_compiles() == 0, (
+        f"{n_compiles()} XLA compiles leaked past warmup "
         f"(cold: {eng.warmup_report()['cold_names']})")
     assert eng.warmup_report()["post_warmup_compiles"] == 0
     assert all(h.finished for h in handles)
