@@ -56,6 +56,7 @@ from ..models.config import ArchConfig
 from ..models import backends as bk
 from ..models import transformer as T
 from .host_loop import HostLoop, TokenDelivery
+from .tracing import span
 from .warmup import ExecutableCache, avatar
 
 
@@ -545,11 +546,12 @@ class Engine:
             clock=self._clock) \
             if async_host else None
         self._rehearse_s: Optional[float] = None
-        self._counters = {"admitted": 0, "queue_wait_ticks": 0,
+        self._counters = {"admitted": 0,
                           "pool_exhausted_stalls": 0, "preemptions": 0,
                           "spilled_blocks": 0, "restored_blocks": 0,
                           "deadline_misses": 0, "cancelled": 0, "shed": 0,
-                          "nan_quarantines": 0, "watchdog_trips": 0}
+                          "nan_quarantines": 0, "watchdog_trips": 0,
+                          "decode_syncs": 0, "decode_call_s": 0.0}
 
         # ----- degradation ladder + fault model (DESIGN.md §11) -----
         if step_timeout_s is not None and step_timeout_s <= 0:
@@ -564,6 +566,7 @@ class Engine:
         self._watchdog_consec = 0
         self._wedged = False
         self._tick = 0
+        self._tick_prefill = 0          # prompt tokens prefilled this tick
         self._last_stall_tick = -1      # one stall increment per tick (§11)
         self._admit_seq = 0             # activation order, for victim policy
         self._slot_seq = np.zeros((b,), np.int64)
@@ -748,17 +751,35 @@ class Engine:
         when there is nothing left to do; a non-empty queue that cannot
         admit (pool pressure, chaos-seized blocks) keeps returning True so
         ``run`` never abandons queued work — the no-deadlock contract of
-        DESIGN.md §11."""
+        DESIGN.md §11.
+
+        Each phase runs inside a host span (``engine.lifecycle``,
+        ``engine.retire``, ``engine.admit``, ``engine.prefill_chunk``,
+        ``engine.cow``, ``engine.flush_tables``, ``engine.decode.dispatch``,
+        ``engine.decode.wait``, ``engine.deliver``), all inside
+        ``engine.step``, whose metadata is :meth:`gauges` at the tick's end
+        plus ``tick`` and ``prefill_tokens`` (DESIGN.md §10)."""
         self._tick += 1
+        self._tick_prefill = 0
+        with span("engine.step", self._step_counters):
+            return self._step()
+
+    def _step_counters(self) -> dict:
+        return dict(self.gauges(), tick=self._tick,
+                    prefill_tokens=self._tick_prefill)
+
+    def _step(self) -> bool:
         tick = getattr(self._clock, "tick", None)
         if callable(tick):
             tick()                       # deterministic virtual clocks
         if self._faults is not None:
             self._faults.on_tick(self)
-        self._lifecycle()
-        self._retire()
-        self._admit()
-        self._counters["queue_wait_ticks"] += len(self._queue)
+        with span("engine.lifecycle"):
+            self._lifecycle()
+        with span("engine.retire"):
+            self._retire()
+        with span("engine.admit"):
+            self._admit()
         self._prefill_tick()
         active = [i for i in range(self.batch_slots)
                   if self._slot_handle[i] is not None]
@@ -771,7 +792,8 @@ class Engine:
             if self._wedged:
                 self._shed_all()          # watchdog abort: terminate clean
                 return False
-        self._retire()
+        with span("engine.retire"):
+            self._retire()
         return True
 
     def run(self, handles: Optional[List[StreamHandle]] = None) -> None:
@@ -812,6 +834,22 @@ class Engine:
     def active_slots(self) -> int:
         """Decode lanes currently occupied (DESIGN.md §10 metrics gauge)."""
         return sum(h is not None for h in self._slot_handle)
+
+    def gauges(self) -> dict:
+        """Scheduler and pool gauges at this instant (DESIGN.md §10):
+        queued requests, occupied decode lanes, undelivered host-loop
+        items, and the paged pool's used, reserved and total blocks summed
+        over bands (all 0 for a striped engine).  The ``engine.step`` span
+        carries them as metadata; ``MetricsRecorder.on_step`` samples
+        them."""
+        pools = self._pools.values()
+        return {"queue_depth": len(self._queue),
+                "active_slots": self.active_slots,
+                "host_queue_depth": (self._host.queue_depth
+                                     if self._host is not None else 0),
+                "pool_used": sum(p.used() for p in pools),
+                "pool_reserved": sum(p.reserved() for p in pools),
+                "pool_blocks": sum(p.n_blocks for p in pools)}
 
     # ------------------------------------------------- warmup (DESIGN.md §10)
 
@@ -953,8 +991,8 @@ class Engine:
         self._wedged = False
         if self._spill is not None:          # rehearsal spills don't count
             self._spill = HostSpillTier(self._spill.budget_bytes)
-        for k in self._counters:
-            self._counters[k] = 0
+        for k, v in self._counters.items():
+            self._counters[k] = type(v)()
         for pool in self._pools.values():
             pool.hits = pool.misses = pool.cow_copies = 0
             pool.peak_used = pool.used()
@@ -1014,9 +1052,11 @@ class Engine:
 
         ``counters`` (DESIGN.md §10) are cumulative since engine build (or
         since :meth:`warmup`, which restores them): requests admitted,
-        request-ticks spent queued, ticks the FIFO head stalled on an
-        exhausted pool, and CoW copies; ``host`` carries the async host
-        loop's delivery/backpressure counters when enabled."""
+        ticks the FIFO head stalled on an exhausted pool, CoW copies,
+        ``decode_syncs`` (decode calls) and ``decode_call_s`` (the engine
+        clock's seconds from each call's dispatch until its outputs reached
+        the host, summed); ``host`` carries the async host loop's
+        delivery/backpressure counters when enabled."""
         out: dict = {"pooled": bool(self._pools),
                      "queue_depth": len(self._queue),
                      "active_slots": self.active_slots,
@@ -1605,25 +1645,28 @@ class Engine:
         drop their prefix-hash registration — they are about to diverge
         from the content the hash names."""
         sps, bt = self.steps_per_sync, self.pool_block_tokens
-        for group, bkey, bs, be, pol, nb in self._pool_bands:
-            pool = self._pools[(group, bkey)]
-            pairs = []
-            for i in range(self.batch_slots):
-                if self._slot_handle[i] is None:
-                    continue
-                u_lo = int(self._hostlen[i]) - pol.n_sink - pol.window
-                for lb in seg.blocks_spanned(u_lo, u_lo + sps, bt, nb):
-                    work = pool.ensure_writable(i, lb)
-                    if work is not None and work[0] == "copy":
-                        pairs.append((work[1], work[2]))
-            if pairs:
-                arr = np.zeros((self._cow_cap(), 2), np.int32)
-                arr[:len(pairs)] = pairs
-                self._set_band_cache(
-                    group, bkey,
-                    self._call("pool_copy", self._pool_copy(),
-                               self._band_cache_ref(group, bkey),
-                               jnp.asarray(arr)))
+        copies = 0
+        with span("engine.cow", lambda: {"copies": copies}):
+            for group, bkey, bs, be, pol, nb in self._pool_bands:
+                pool = self._pools[(group, bkey)]
+                pairs = []
+                for i in range(self.batch_slots):
+                    if self._slot_handle[i] is None:
+                        continue
+                    u_lo = int(self._hostlen[i]) - pol.n_sink - pol.window
+                    for lb in seg.blocks_spanned(u_lo, u_lo + sps, bt, nb):
+                        work = pool.ensure_writable(i, lb)
+                        if work is not None and work[0] == "copy":
+                            pairs.append((work[1], work[2]))
+                if pairs:
+                    copies += len(pairs)
+                    arr = np.zeros((self._cow_cap(), 2), np.int32)
+                    arr[:len(pairs)] = pairs
+                    self._set_band_cache(
+                        group, bkey,
+                        self._call("pool_copy", self._pool_copy(),
+                                   self._band_cache_ref(group, bkey),
+                                   jnp.asarray(arr)))
 
     def _flush_tables(self):
         """Push dirty host block tables to the device caches.  Rows of
@@ -1644,6 +1687,7 @@ class Engine:
 
     def _admit_group(self, handles: List[StreamHandle], slots: List[int]):
         prompts = np.stack([h.request.prompt for h in handles])
+        self._tick_prefill += prompts.size
         logits, caches = self._call(
             "prefill", self.prefill_fn, self.params,
             {"tokens": jnp.asarray(prompts, jnp.int32)})
@@ -1702,10 +1746,12 @@ class Engine:
         bucket = next(b for b in self.chunk_buckets if b >= n)
         toks = np.zeros((1, bucket), np.int32)
         toks[0, :n] = prompt[job.pos:job.pos + n]
-        logits, job.state = self._call(
-            f"chunk_{bucket}", self._chunk_fn(bucket),
-            self.params, jnp.asarray(toks), job.state,
-            jnp.int32(job.pos), jnp.int32(n))
+        with span("engine.prefill_chunk", lambda: {"bucket": bucket, "n": n}):
+            logits, job.state = self._call(
+                f"chunk_{bucket}", self._chunk_fn(bucket),
+                self.params, jnp.asarray(toks), job.state,
+                jnp.int32(job.pos), jnp.int32(n))
+        self._tick_prefill += n
         job.pos += n
         if job.pos >= len(prompt):
             self._prefill_job = None
@@ -1810,78 +1856,89 @@ class Engine:
     def _decode_chunk(self):
         if self._pools:
             self._pool_prewrite()
-            self._flush_tables()
+            with span("engine.flush_tables"):
+                self._flush_tables()
         t0 = self._clock()
-        toks, tok, caches, keys, done, bad, live = self._call(
-            "multi", self._multi_fn(),
-            self.params, jnp.asarray(self._tok), self._caches,
-            jnp.asarray(self._keys), jnp.asarray(self._done),
-            jnp.asarray(self._temps), jnp.asarray(self._eos),
-            jnp.asarray(self._nan_inject))
+        with span("engine.decode.dispatch"):
+            toks, tok, caches, keys, done, bad, live = self._call(
+                "multi", self._multi_fn(),
+                self.params, jnp.asarray(self._tok), self._caches,
+                jnp.asarray(self._keys), jnp.asarray(self._done),
+                jnp.asarray(self._temps), jnp.asarray(self._eos),
+                jnp.asarray(self._nan_inject))
         self._caches = caches
-        # np.array copies: jax->numpy views are read-only and the scheduler
-        # mutates these in place at retire/admit time
-        self._tok = np.array(tok)
-        self._keys = np.array(keys)
-        done_np = self._done = np.array(done)
-        bad_np = np.asarray(bad)
+        with span("engine.decode.wait"):
+            # np.array copies: jax->numpy views are read-only and the
+            # scheduler mutates these in place at retire/admit time
+            self._tok = np.array(tok)
+            self._keys = np.array(keys)
+            done_np = self._done = np.array(done)
+            bad_np = np.asarray(bad)
+            if self._host is not None:
+                live = np.asarray(live)
         # one-shot injections reset only AFTER the outputs above forced the
         # computation: jnp.asarray(self._nan_inject) may alias the numpy
         # buffer on CPU, so zeroing before the sync races the device read
         self._nan_inject[:] = False
-        self._watchdog(self._clock() - t0)
-        if self._host is not None:
-            # async (DESIGN.md §10): decide finishes from the tiny per-slot
-            # live counts; the big token array stays on device and the
-            # consumer thread materializes it off the scheduler's critical
-            # path
-            live = np.asarray(live)
-            handles, rows, counts, reasons = [], [], [], []
+        dt = self._clock() - t0
+        self._counters["decode_syncs"] += 1
+        self._counters["decode_call_s"] += dt
+        self._watchdog(dt)
+        with span("engine.deliver"):
+            if self._host is not None:
+                self._enqueue_chunk(toks, done_np, bad_np, live)
+                return
+            toks = np.asarray(toks)                 # ONE sync per chunk
             for i in range(self.batch_slots):
                 h = self._slot_handle[i]
-                if h is None or h._sched_fin is not None:
+                if h is None:
                     continue
                 self._hostlen[i] += self.steps_per_sync
-                if bool(bad_np[i]):
-                    # NaN quarantine (§11): the slot's logits went
-                    # non-finite — drop the chunk, shed the stream
+                if bool(bad_np[i]) and not h.finished:
                     self._counters["nan_quarantines"] += 1
-                    h._sched_fin = FinishReason.SHED
-                    handles.append(h)
-                    rows.append(i)
-                    counts.append(0)
-                    reasons.append(FinishReason.SHED)
+                    self._finish(h, FinishReason.SHED)  # retire frees the slot
                     continue
-                left = h.request.max_new - h._sched_consumed
-                n_live = int(live[i])
-                if bool(done_np[i]) and n_live <= left:
-                    consumed, reason = n_live, FinishReason.EOS
-                elif left <= n_live:
-                    consumed, reason = left, FinishReason.LENGTH
-                else:
-                    consumed, reason = n_live, None
-                h._sched_consumed += consumed
-                h._sched_fin = reason
-                handles.append(h)
-                rows.append(i)
-                counts.append(consumed)
-                reasons.append(reason)
-            if handles:
-                self._host.put(TokenDelivery(
-                    handles=handles, rows=rows, counts=counts,
-                    reasons=reasons, tokens=toks))
-            return
-        toks = np.asarray(toks)                 # ONE sync per chunk
+                self._deliver(i, toks[i].tolist())
+
+    def _enqueue_chunk(self, toks, done_np, bad_np, live):
+        """Async delivery of one decode chunk (DESIGN.md §10): decide
+        finishes from the tiny per-slot live counts; the big token array
+        stays on device and the consumer thread materializes it off the
+        scheduler's critical path."""
+        handles, rows, counts, reasons = [], [], [], []
         for i in range(self.batch_slots):
             h = self._slot_handle[i]
-            if h is None:
+            if h is None or h._sched_fin is not None:
                 continue
             self._hostlen[i] += self.steps_per_sync
-            if bool(bad_np[i]) and not h.finished:
+            if bool(bad_np[i]):
+                # NaN quarantine (§11): the slot's logits went
+                # non-finite — drop the chunk, shed the stream
                 self._counters["nan_quarantines"] += 1
-                self._finish(h, FinishReason.SHED)   # retire frees the slot
+                h._sched_fin = FinishReason.SHED
+                handles.append(h)
+                rows.append(i)
+                counts.append(0)
+                reasons.append(FinishReason.SHED)
                 continue
-            self._deliver(i, toks[i].tolist())
+            left = h.request.max_new - h._sched_consumed
+            n_live = int(live[i])
+            if bool(done_np[i]) and n_live <= left:
+                consumed, reason = n_live, FinishReason.EOS
+            elif left <= n_live:
+                consumed, reason = left, FinishReason.LENGTH
+            else:
+                consumed, reason = n_live, None
+            h._sched_consumed += consumed
+            h._sched_fin = reason
+            handles.append(h)
+            rows.append(i)
+            counts.append(consumed)
+            reasons.append(reason)
+        if handles:
+            self._host.put(TokenDelivery(
+                handles=handles, rows=rows, counts=counts,
+                reasons=reasons, tokens=toks))
 
     def _watchdog(self, dt: float):
         """Device-step watchdog (DESIGN.md §11): a decode chunk exceeding

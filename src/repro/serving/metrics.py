@@ -148,17 +148,14 @@ class MetricsRecorder:
         self._handles[handle.rid] = handle
 
     def on_step(self, engine, now_s: float) -> None:
-        """Sample per-step queue/occupancy gauges (DESIGN.md §10)."""
-        sample = {
-            "t": now_s,
-            "queue_depth": engine.queue_depth,
-            "active_slots": engine.active_slots,
-            "host_queue_depth": (engine._host.queue_depth
-                                 if getattr(engine, "_host", None) else 0),
-        }
-        if engine._pools:
-            sample["pool_used"] = sum(
-                p.used() for p in engine._pools.values())
+        """Sample per-step queue/occupancy gauges (``Engine.gauges()``,
+        DESIGN.md §10)."""
+        g = engine.gauges()
+        sample = {"t": now_s, "queue_depth": g["queue_depth"],
+                  "active_slots": g["active_slots"],
+                  "host_queue_depth": g["host_queue_depth"]}
+        if g["pool_blocks"]:
+            sample["pool_used"] = g["pool_used"]
         self.samples.append(sample)
 
     def finalize(self) -> None:

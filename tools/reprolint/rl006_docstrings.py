@@ -39,6 +39,7 @@ AUDITED = [
     "repro.serving.metrics",
     "repro.serving.faults",
     "repro.core.block_pool",
+    "repro.serving.tracing",
 ]
 
 CITE_RE = re.compile(r"DESIGN\.md §(\w+)")
