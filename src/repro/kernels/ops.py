@@ -29,7 +29,7 @@ from ..core.policy import QuantPolicy
 from ..core.quant import n_meta_groups, packed_nbytes
 from ..core import segments as seg
 from ..core.kv_cache import slot_lengths as kvc_slot_lengths
-from .decode_attn import decode_attn_pallas, BLOCK_S
+from .decode_attn import decode_attn_pallas, pages_per_block, BLOCK_S
 from .kv_quant import kv_quant_pallas
 
 # bit pattern of float8_e4m3fn(1.0): sign 0, exponent 0111 (bias 7), mantissa 0
@@ -150,10 +150,12 @@ def pallas_decode_attention(q, cache, policy: QuantPolicy, *, scale: float,
 
     Pooled caches (DESIGN.md §9) take the fast path: the per-slot
     ``block_tbl`` scalar-prefetches into the kernel alongside the pruning
-    bounds and the plane BlockSpecs gather physical blocks in-kernel — no
-    host-side gather, no recompiles as tables change, and the tile grid is
-    the pool's ``block_tokens`` so the flash merge order (hence bits) maps
-    onto a striped run at ``block_s == block_tokens``.  The ``local_slice``
+    bounds (in pages), and the kernel copies each live page of the table
+    itself — no host-side gather, no recompiles as tables change.  Its
+    tile is a compute block of ``decode_attn.pages_per_block`` pages
+    (``BLOCK_S`` tokens at 16-token pages), so the flash merge order (hence
+    bits) maps onto a striped run at ``block_s == P * block_tokens``; the
+    ``block_s`` argument does not apply to it.  The ``local_slice``
     and ``packed_override`` levers pre-slice plane tensors, which has no
     pooled analogue — those calls fall back to the gathered striped view
     (``kv_cache.unpool_cache``), still bit-identical.
@@ -198,10 +200,9 @@ def pallas_decode_attention(q, cache, policy: QuantPolicy, *, scale: float,
     else:
         s_q = cache["qk_codes_hi"].shape[1] if "qk_codes_hi" in cache else 0
     if pooled:
-        # pooled fast path: planes stay pool-major; the kernel remaps
-        # physical blocks via the prefetched table.  The logical capacity
-        # already tiles into block_tokens, so no padding is needed and the
-        # mask/bounds math runs in logical coordinates exactly as striped.
+        # pooled fast path: planes stay pool-major; the kernel copies the
+        # physical pages of the prefetched table itself.  The mask and the
+        # page bounds run in logical coordinates exactly as striped.
         k_qt = {kk[3:]: vv for kk, vv in cache.items()
                 if kk.startswith("qk_")}
         v_qt = {kk[3:]: vv for kk, vv in cache.items()
@@ -285,9 +286,15 @@ def decode_block_report(cache, policy: QuantPolicy, head_dim: int, *,
     (DESIGN.md §4):
 
     ``bounds``      (B, 2) live block range [lo, hi) per slot
-    ``visited``     (B,)   blocks the pruned kernel DMAs (>= 1 per slot)
+    ``visited``     (B,)   blocks in the live range (>= 1 per slot: the
+                    blocks the pruned striped kernel DMAs)
     ``total``       int    capacity blocks the unpruned kernel walks
     ``bytes_per_block`` int packed-plane bytes one block moves (all kv heads)
+    ``pages_per_block`` int blocks per kernel compute block: the pooled
+                    kernel's ``P`` (its blocks are pool pages), 1 striped
+    ``compute_blocks_visited`` (B,) compute blocks the kernel runs: live
+                    ``P``-page blocks pooled (0 for an empty slot),
+                    ``visited`` striped
 
     Estimated packed bytes/step = ``visited.sum() * bytes_per_block`` pruned
     vs ``B * total * bytes_per_block`` unpruned — the blocks-visited and
@@ -305,7 +312,8 @@ def decode_block_report(cache, policy: QuantPolicy, head_dim: int, *,
     if s_q == 0 or policy.is_fp16:
         zeros = jnp.zeros((b,), jnp.int32)
         return {"bounds": jnp.zeros((b, 2), jnp.int32), "visited": zeros,
-                "total": 0, "bytes_per_block": 0}
+                "total": 0, "bytes_per_block": 0, "pages_per_block": 1,
+                "compute_blocks_visited": zeros}
     t_now = lens - 1 if q_pos is None else jnp.broadcast_to(
         jnp.asarray(q_pos), (b,))
     weff = seg.effective_window(window)
@@ -322,8 +330,15 @@ def decode_block_report(cache, policy: QuantPolicy, head_dim: int, *,
                              policy.meta_dtype_bits) +
                packed_nbytes(head_dim, policy.bits_v, gsz,
                              policy.meta_dtype_bits))
-    return {"bounds": bounds, "visited": seg.blocks_visited(bounds),
-            "total": s_pad // bs, "bytes_per_block": bs * hkv * per_tok}
+    visited = seg.blocks_visited(bounds)
+    ppb, compute_blocks = 1, visited
+    if pooled:
+        ppb = pages_per_block(bs, s_pad // bs)
+        lo, hi = bounds[:, 0], bounds[:, 1]
+        compute_blocks = jnp.where(hi > lo, -(-hi // ppb) - lo // ppb, 0)
+    return {"bounds": bounds, "visited": visited, "total": s_pad // bs,
+            "bytes_per_block": bs * hkv * per_tok, "pages_per_block": ppb,
+            "compute_blocks_visited": compute_blocks}
 
 
 @functools.partial(jax.jit, static_argnames=("policy", "head_dim", "scale",

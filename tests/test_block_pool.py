@@ -4,9 +4,9 @@ sharing, and pooled-vs-striped decode parity (DESIGN.md §9).
 Acceptance for the pool redesign:
   (a) pooled decode is bit-identical to the striped layout on BOTH
       backends, for uniform and mixed PolicySchedules, whole-prompt and
-      chunked prefill — the pallas striped baseline runs at
-      ``block_s == pool_block_tokens`` so the tile grid and flash merge
-      order match exactly;
+      chunked prefill — the pallas striped baseline runs at the pooled
+      kernel's compute block, ``block_s == P * pool_block_tokens``, so the
+      tile grid and flash merge order match exactly;
   (b) block tables are *data*: ragged traffic through the pooled engine
       never recompiles the decode executable;
   (c) identical prompt prefixes quantize once and share blocks
@@ -25,6 +25,7 @@ from repro.core.policy import QuantPolicy, PolicySchedule
 from repro.core import kv_cache as kvc
 from repro.core import segments as seg
 from repro.core.block_pool import BlockPool, prefix_block_keys
+from repro.kernels.decode_attn import pages_per_block
 from repro.models.config import ArchConfig
 from repro.models import backends as bk
 from repro.models import transformer as T
@@ -255,7 +256,8 @@ BANDED = PolicySchedule(layers=(
 @pytest.mark.parametrize("policy", [POL, MIXED, BANDED],
                          ids=["uniform", "fp16_guard", "two_band"])
 def test_pooled_engine_bit_parity(params, rng, backend_name, policy):
-    backend = (bk.PallasBackend(block_s=BT) if backend_name == "pallas"
+    tile = pages_per_block(BT, (MAX_LEN - POL.n_sink - POL.window) // BT) * BT
+    backend = (bk.PallasBackend(block_s=tile) if backend_name == "pallas"
                else "reference")
     prompts = _prompts(rng, [40, 40, 33, 50, 27])
     striped = _run(params, policy, prompts, backend=backend)

@@ -96,6 +96,10 @@ def test_zero_packed_slot_all_window(rng):
     lo, hi = np.asarray(rep["bounds"])[0]
     assert lo == hi == 0
     assert int(np.asarray(rep["visited"])[0]) == 1
+    # striped: each block is its own compute block
+    assert rep["pages_per_block"] == 1
+    np.testing.assert_array_equal(np.asarray(rep["compute_blocks_visited"]),
+                                  np.asarray(rep["visited"]))
 
 
 def test_windowed_layer_lower_bound(rng):

@@ -89,21 +89,27 @@ def test_decode_attn_striped_compiles(one_chip, d):
              sds((B, S_LEN), jnp.float32), sds((B, 2), jnp.int32))
 
 
-@pytest.mark.parametrize("d", [64, 128])
-def test_decode_attn_pooled_compiles(one_chip, d):
+@pytest.mark.parametrize("d,hkv,gq,n_pages,n_phys", [
+    pytest.param(64, HKV, GQ, S_LEN // POOL_BLOCK_TOKENS, 512, id="64"),
+    pytest.param(128, HKV, GQ, S_LEN // POOL_BLOCK_TOKENS, 512, id="128"),
+    # qwen2-7b.longctx-decode: 4 KV heads of 7 query heads, 1,535 pages a
+    # slot, every slot's pages in the pool plus the null page
+    pytest.param(128, 4, 7, 1535, 4 * 1535 + 1, id="longctx-cell"),
+])
+def test_decode_attn_pooled_compiles(one_chip, d, hkv, gq, n_pages, n_phys):
     sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
-    bt, n_phys = POOL_BLOCK_TOKENS, 512
-    lead = (n_phys, bt, HKV)
+    bt = POOL_BLOCK_TOKENS
+    lead = (n_phys, bt, hkv)
 
     def fn(q, k, v, mask, bounds, table):
         return decode_attn_pallas(q, k, v, mask, POL, d, d ** -0.5,
                                   interpret=False, block_s=bt,
                                   block_bounds=bounds, block_table=table)
-    _compile(fn, sds((B, HKV, GQ, d), jnp.float32),
+    _compile(fn, sds((B, hkv, gq, d), jnp.float32),
              _planes(one_chip, lead, d, POL.bits_k),
              _planes(one_chip, lead, d, POL.bits_v),
-             sds((B, S_LEN), jnp.float32), sds((B, 2), jnp.int32),
-             sds((B, S_LEN // bt), jnp.int32))
+             sds((B, n_pages * bt), jnp.float32), sds((B, 2), jnp.int32),
+             sds((B, n_pages), jnp.int32))
 
 
 @pytest.mark.parametrize("bits", [2.0, 1.5])
