@@ -3,12 +3,18 @@
 Kept with the benchmark so a change to the program cannot change the
 yardstick; ``tests/bench`` checks the byte counts against the program's
 own accounting (``kv_cache.pool_block_nbytes``, ``ops.decode_block_report``).
+What depends on the block's equations (operations a token passes through,
+how much of the cache each layer attends over) is the family's
+(``bench/families/<family>.py``); bytes per token and the sums over steps
+are here.
 """
 from __future__ import annotations
 
 import json
 from pathlib import Path
 from typing import Iterable, Tuple
+
+from . import spec
 
 PEAKS = Path(__file__).resolve().parent / "peaks.json"
 
@@ -68,29 +74,18 @@ def live_packed_tokens(length: int, pol: dict) -> int:
 
 
 def decode_attn_bytes(contexts, dims: dict, pol: dict) -> int:
-    """Packed bytes the decode kernel must read over these steps: every
-    layer reads every live packed token of every slot once per step."""
-    per = kv_bytes_per_token_layer(dims, pol) * dims["num_hidden_layers"]
-    return per * sum(live_packed_tokens(n, pol) for n in decode_steps(contexts))
-
-
-def weight_flops_per_token(dims: dict) -> int:
-    """2 x multiply-adds of the matrices one token passes through."""
-    d, f = dims["hidden_size"], dims["intermediate_size"]
-    q = dims["num_attention_heads"] * dims["head_dim"]
-    kv = dims["num_key_value_heads"] * dims["head_dim"]
-    layer = d * q + 2 * d * kv + q * d + 3 * d * f
-    return 2 * (dims["num_hidden_layers"] * layer + d * dims["vocab_size"])
-
-
-def attn_flops(dims: dict, length: int) -> int:
-    """Scores and weighted values of one query over ``length`` keys, in all
-    layers."""
-    q = dims["num_attention_heads"] * dims["head_dim"]
-    return 4 * q * length * dims["num_hidden_layers"]
+    """Packed bytes the decode kernel must read over these steps: each
+    layer reads every live packed token of the length it attends over
+    (the family's ``attended_lengths``) once."""
+    fam = spec.family(dims["family"])
+    tokens = sum(live_packed_tokens(n, pol) for s in decode_steps(contexts)
+                 for n in fam.attended_lengths(dims, s))
+    return kv_bytes_per_token_layer(dims, pol) * tokens
 
 
 def decode_flops(contexts, dims: dict) -> int:
-    """Operations of every decode step in ``contexts`` (one token each)."""
-    w = weight_flops_per_token(dims)
-    return sum(w + attn_flops(dims, n) for n in decode_steps(contexts))
+    """Operations of every decode step in ``contexts`` (one token each):
+    the family's weight and attention counts."""
+    fam = spec.family(dims["family"])
+    w = fam.weight_flops_per_token(dims)
+    return sum(w + fam.attn_flops(dims, n) for n in decode_steps(contexts))

@@ -1,43 +1,30 @@
 """The system under test, built from a cell: configuration, policy, engine.
 
-This is the one module of the benchmark that imports the serving program
-(``src/repro``); the reference (:mod:`bench.reference`) does not.
+This module, and each family's ``arch`` (``bench/families``), are the only
+code of the benchmark that imports the serving program (``src/repro``); the
+reference (:mod:`bench.reference`) does not.
 """
 from __future__ import annotations
 
 import copy
 import sys
 
+from . import spec
 from .spec import ROOT
 
 if str(ROOT / "src") not in sys.path:
     sys.path.insert(0, str(ROOT / "src"))
 
-DIM_KEYS = ("num_hidden_layers", "hidden_size", "num_attention_heads",
-            "num_key_value_heads", "head_dim", "intermediate_size",
-            "vocab_size", "rope_theta", "rms_norm_eps", "hidden_act",
-            "attention_bias", "tie_word_embeddings")
-
 
 def dims(config: dict, smoke: bool = False) -> dict:
-    """The model sizes as run (the smoke block overrides them in tests)."""
-    out = {k: config[k] for k in DIM_KEYS}
-    if smoke:
-        out.update(config["smoke"])
-    return out
+    """The model sizes as run (the smoke block overrides them in tests),
+    by the family the configuration names (``bench/families``)."""
+    return spec.family(config["family"]).dims(config, smoke)
 
 
 def arch(d: dict):
-    """The program's ``ArchConfig`` for these sizes (dense family)."""
-    from repro.models.config import ArchConfig
-    return ArchConfig(
-        name="bench", family="dense", n_layers=d["num_hidden_layers"],
-        d_model=d["hidden_size"], n_heads=d["num_attention_heads"],
-        n_kv_heads=d["num_key_value_heads"], head_dim=d["head_dim"],
-        d_ff=d["intermediate_size"], vocab_size=d["vocab_size"],
-        rope_theta=float(d["rope_theta"]), qkv_bias=bool(d["attention_bias"]),
-        mlp_act=d["hidden_act"], tie_embeddings=bool(d["tie_word_embeddings"]),
-        norm_eps=float(d["rms_norm_eps"]))
+    """The program's ``ArchConfig`` for these sizes, by their family."""
+    return spec.family(d["family"]).arch(d)
 
 
 def policy(config: dict, d: dict):

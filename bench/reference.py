@@ -3,17 +3,23 @@
 A straightforward forward pass in ``jax.numpy`` at
 ``default_matmul_precision("highest")``, written from the model's
 equations and the SKVQ paper, importing nothing of the program.  It takes
-the bf16 weights from the seed (:mod:`bench.weights`) and computes in fp32:
+the bf16 weights from the seed (:mod:`bench.weights`) and computes in fp32.
+The equations of a model family's block and head, and its position tables,
+live in ``bench/families/<family>.py`` (``layer``, ``head``, ``tables``);
+this module holds what every family shares:
 
-* pre-norm decoder: RMSNorm with gain ``1 + w``, q/k/v projections (+bias),
-  rotate-half RoPE at absolute positions, grouped-query attention with scale
-  ``head_dim ** -0.5``, SwiGLU MLP, final RMSNorm, head (tied or not);
-* prompt positions attend to the prompt in full precision (the paper's
-  full-precision prefill);
-* a served position ``t`` attends to keys and values ``j`` quantized and
-  dequantized (per token and KV head, groups of channels, min/max clipped,
-  scale and zero rounded to fp8 E4M3) where ``n_sink <= j <= t - window``,
-  and in full precision elsewhere: the sinks and the window.
+* RMSNorm with gain ``1 + w``, rotate-half RoPE from a table, and matrix
+  products (:func:`rms`, :func:`rope`, :func:`mm`);
+* SKVQ attention (:func:`attend`): prompt positions attend to the prompt
+  in full precision (the paper's full-precision prefill); a served
+  position ``t`` attends to keys and values ``j`` quantized and dequantized
+  (per token and KV head, groups of channels, min/max clipped, scale and
+  zero rounded to fp8 E4M3; :func:`fake_quant`) where
+  ``n_sink <= j <= t - window``, and in full precision elsewhere: the sinks
+  and the window; a layer with a sliding window of ``w`` sees only
+  ``t - w < j <= t``;
+* the walk over the layers and the scoring (:func:`logits`, :func:`gaps`,
+  :func:`control_gaps`).
 
 Two steps are spelled out so the result does not depend on the device it
 runs on: the E4M3 rounding is done in arithmetic (on a TPU, XLA drops a
@@ -37,6 +43,8 @@ from typing import Dict, List, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from . import spec
 
 E4M3 = jnp.float8_e4m3fn
 E4M3_MAX = 448.0
@@ -62,7 +70,7 @@ def _fp8(x):
     return jnp.ldexp(jnp.round(jnp.ldexp(x, -step)), step)
 
 
-def _fp8_scaled(x, axis):
+def fp8_scaled(x, axis):
     """E4M3 rounding of ``x`` scaled so its largest entry along ``axis``
     meets the format's largest value (the control's operands)."""
     s = jnp.max(jnp.abs(x), axis=axis, keepdims=True) / E4M3_MAX
@@ -95,7 +103,8 @@ def _f32(x):
     return x.astype(jnp.float32)
 
 
-def _rms(x, w, eps):
+def rms(x, w, eps):
+    """RMSNorm of fp32 ``x`` over its last axis, with gain ``1 + w``."""
     return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * (
         1.0 + w.astype(jnp.float32))
 
@@ -111,15 +120,18 @@ def rope_table(n: int, head_dim: int, theta: float):
             jnp.asarray(np.sin(ang), jnp.float32))
 
 
-def _rope(x, cs):
+def rope(x, cs):
+    """Rotate-half RoPE of ``x`` (S, H, D) by a table ``(cos, sin)``."""
     half = x.shape[-1] // 2
     cos, sin = cs[0][:, None], cs[1][:, None]
     x1, x2 = x[..., :half], x[..., half:]
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
-def _attend(q, k, v, kq, vq, n_prompt, pol):
-    """q (S, Hq, D), k/v/kq/vq (S, Hkv, D) -> (S, Hq, D), by query blocks."""
+def attend(q, k, v, kq, vq, n_prompt, pol, window: int = 0):
+    """q (S, Hq, D), k/v/kq/vq (S, Hkv, D) -> (S, Hq, D), by query blocks.
+    ``window`` (a Python int): 0 attends causally over the whole sequence;
+    ``w > 0`` lets the query at ``t`` see only keys ``t - w < j <= t``."""
     s, hq, d = q.shape
     hkv = k.shape[1]
     g = hq // hkv
@@ -130,7 +142,9 @@ def _attend(q, k, v, kq, vq, n_prompt, pol):
     def block(args):
         i, qx = args
         t = i * Q_BLOCK + jnp.arange(Q_BLOCK)
-        causal = j[None, :] <= t[:, None]
+        seen = j[None, :] <= t[:, None]
+        if window:
+            seen = seen & (j[None, :] > t[:, None] - window)
         s_fp = jnp.einsum("qhgd,khd->hgqk", qx, k) * scale
 
         def served(_):
@@ -138,12 +152,12 @@ def _attend(q, k, v, kq, vq, n_prompt, pol):
                     & (j[None, :] <= t[:, None] - pol["window"]))
             s_q = jnp.einsum("qhgd,khd->hgqk", qx, kq) * scale
             sc = jnp.where(useq, s_q, s_fp)
-            p = jax.nn.softmax(jnp.where(causal, sc, -jnp.inf), -1)
+            p = jax.nn.softmax(jnp.where(seen, sc, -jnp.inf), -1)
             return (jnp.einsum("hgqk,khd->qhgd", p * useq, vq)
                     + jnp.einsum("hgqk,khd->qhgd", p * ~useq, v))
 
         def prompt(_):
-            p = jax.nn.softmax(jnp.where(causal, s_fp, -jnp.inf), -1)
+            p = jax.nn.softmax(jnp.where(seen, s_fp, -jnp.inf), -1)
             return jnp.einsum("hgqk,khd->qhgd", p, v)
 
         return jax.lax.cond(t[-1] >= n_prompt, served, prompt, None)
@@ -152,58 +166,26 @@ def _attend(q, k, v, kq, vq, n_prompt, pol):
     return out.reshape(s, hq, d)
 
 
-def _mm(x, w, prec):
+def mm(x, w, prec):
     """``x @ w`` with fp32 operands, or, for the control, E4M3 ones."""
     w = _f32(w)
     if prec == "fp8":
-        x, w = _fp8_scaled(x, -1), _fp8_scaled(w, 0)
+        x, w = fp8_scaled(x, -1), fp8_scaled(w, 0)
     return x @ w
 
 
-@functools.partial(jax.jit, static_argnames=("dims_t", "pol_t", "prec"))
-def _layer(h, lw, cs, n_prompt, dims_t, pol_t, prec):
-    dims, pol = dict(dims_t), dict(pol_t)
-    s = h.shape[0]
-    hq, hkv, hd = (dims["num_attention_heads"], dims["num_key_value_heads"],
-                   dims["head_dim"])
-    eps = dims["rms_norm_eps"]
-    a = lw["attn"]
-    x = _rms(h, lw["norm1"]["w"], eps)
-    q = _mm(x, a["wq"], prec)
-    k = _mm(x, a["wk"], prec)
-    v = _mm(x, a["wv"], prec)
-    if "bq" in a:
-        q, k, v = (q + a["bq"].astype(jnp.float32),
-                   k + a["bk"].astype(jnp.float32),
-                   v + a["bv"].astype(jnp.float32))
-    q = _rope(q.reshape(s, hq, hd), cs)
-    k = _rope(k.reshape(s, hkv, hd), cs)
-    v = v.reshape(s, hkv, hd)
-    if prec == "fp8":
-        q, k, v = (_fp8_scaled(t, -1) for t in (q, k, v))
-    gs = min(pol["group_size"], hd)
-    kq = fake_quant(k, pol["bits_k"], gs, pol["fp8_meta"])
-    vq = fake_quant(v, pol["bits_v"], gs, pol["fp8_meta"])
-    o = _attend(q, k, v, kq, vq, n_prompt, pol).reshape(s, hq * hd)
-    h = h + _mm(o, a["wo_attn"], prec)
-    m = lw["mlp"]
-
-    def mlp(xr):
-        xn = _rms(xr, lw["norm2"]["w"], eps)
-        return xr + _mm(jax.nn.silu(_mm(xn, m["wi_gate"], prec))
-                        * _mm(xn, m["wi_up"], prec), m["wo"], prec)
-
-    rb = min(ROW_BLOCK, s)
-    return jax.lax.map(mlp, h.reshape(s // rb, rb, -1)).reshape(h.shape)
+@functools.partial(jax.jit,
+                   static_argnames=("fam", "dims_t", "pol_t", "prec"))
+def _layer(h, lw, i, tables, n_prompt, fam, dims_t, pol_t, prec):
+    # ``i`` is traced, so one compiled program serves every layer; a family
+    # whose layers differ tells them apart by ``i`` inside its ``layer``
+    return fam.layer(h, lw, i, tables, n_prompt, dict(dims_t), dict(pol_t),
+                     prec)
 
 
-@functools.partial(jax.jit, static_argnames=("dims_t", "prec"))
-def _logits(h, params, rows, dims_t, prec):
-    dims = dict(dims_t)
-    x = _rms(h[rows], params["final_norm"]["w"], dims["rms_norm_eps"])
-    head = (params["embed"].T if dims["tie_word_embeddings"]
-            else params["lm_head"])
-    return _mm(x, head, prec)
+@functools.partial(jax.jit, static_argnames=("fam", "dims_t", "prec"))
+def _head(h, params, rows, fam, dims_t, prec):
+    return fam.head(h, params, rows, dict(dims_t), prec)
 
 
 def _tuple(d: Dict) -> Tuple:
@@ -230,14 +212,16 @@ def logits(params, dims: dict, pol: dict, prompt: np.ndarray,
     d = max(pad_scored, n)
     rows = np.full(d, p - 1 + n - 1, np.int32)
     rows[:n] = p - 1 + np.arange(n)
+    fam = spec.family(dims["family"])
     dims_t, pol_t = _tuple(dims), _tuple(pol)
-    cs = rope_table(s, dims["head_dim"], dims["rope_theta"])
+    tables = fam.tables(dims, s)
     with jax.default_matmul_precision("highest"):
         h = _f32(params["embed"][jnp.asarray(toks)])
         for i in range(dims["num_hidden_layers"]):
             lw = jax.tree.map(lambda x: x[i], params["layers"])
-            h = _layer(h, lw, cs, jnp.int32(p), dims_t, pol_t, prec)
-        out = _logits(h, params, jnp.asarray(rows), dims_t, prec)
+            h = _layer(h, lw, jnp.int32(i), tables, jnp.int32(p), fam,
+                       dims_t, pol_t, prec)
+        out = _head(h, params, jnp.asarray(rows), fam, dims_t, prec)
     return out[:n]
 
 

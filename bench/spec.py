@@ -1,15 +1,19 @@
-"""Finds a cell, its configuration, its traffic mix and its metrics by name.
+"""Finds a cell, its configuration, its traffic mix, its model family and
+its metrics by name.
 
-Everything that belongs to one cell, configuration, mix or per-layer metric
-lives in a file of its own; this module is the only place that knows where:
+Everything that belongs to one cell, configuration, mix, family or
+per-layer metric lives in a file of its own; this module is the only place
+that knows where:
 
     bench/cells/<cell>.json      config, traffic, chips, rate, why, limits
-    bench/configs/<config>.json  model sizes as run, source, cuts, policy
+    bench/configs/<config>.json  model sizes as run, family, source, cuts, policy
     bench/traffic/<mix>.json     loop kind, lengths, prefix, engine knobs
+    bench/families/<family>.py   a family's sizes, weights, reference and costs
     bench/metrics/<metric>.py    ``read(ctx) -> float | None``
 """
 from __future__ import annotations
 
+import functools
 import importlib.util
 import json
 import re
@@ -62,16 +66,39 @@ def metrics_for(cell_name: str, bm: dict, trace: bool) -> List[dict]:
             and m["moves"] in names]
 
 
+def _module(prefix: str, name: str, path: Path):
+    sp = importlib.util.spec_from_file_location(
+        prefix + re.sub(r"[^A-Za-z0-9_]", "_", name), path)
+    mod = importlib.util.module_from_spec(sp)
+    sp.loader.exec_module(mod)
+    return mod
+
+
 def reader(metric: str, root: Path = BENCH) -> Callable[[dict], Optional[float]]:
     """``read`` of ``bench/metrics/<metric>.py``."""
     path = root / "metrics" / f"{_check_name(metric)}.py"
     if not path.is_file():
         raise KeyError(f"no reader for per-layer metric {metric!r} ({path})")
-    modname = "bench_metric_" + re.sub(r"[^A-Za-z0-9_]", "_", metric)
-    sp = importlib.util.spec_from_file_location(modname, path)
-    mod = importlib.util.module_from_spec(sp)
-    sp.loader.exec_module(mod)
-    return mod.read
+    return _module("bench_metric_", metric, path).read
+
+
+def family(name: str, root: Path = BENCH):
+    """The module ``bench/families/<name>.py``: a model family's sizes as
+    run (``dims``), the program's config (``arch``), its weight tree
+    (``shapes``), the reference's position tables, block and head
+    (``tables``, ``layer``, ``head``) and its cost counts
+    (``weight_flops_per_token``, ``attn_flops``, ``attended_lengths``).
+    Loaded once a process, so the reference's compiled programs, keyed by
+    the module, are found again."""
+    path = root / "families" / f"{_check_name(name)}.py"
+    if not path.is_file():
+        raise KeyError(f"no model family named {name!r} ({path} is missing)")
+    return _family(path.resolve())
+
+
+@functools.cache
+def _family(path: Path):
+    return _module("bench_family_", path.stem, path)
 
 
 def workload(bm: dict, name: str) -> Dict:

@@ -47,7 +47,7 @@ def test_request_without_first_token_waits_to_the_end(ctx):
 def _traced(ctx):
     dims = {"num_hidden_layers": 2, "hidden_size": 8, "num_attention_heads": 4,
             "num_key_value_heads": 2, "head_dim": 128, "intermediate_size": 16,
-            "vocab_size": 32}
+            "vocab_size": 32, "family": "dense"}
     pol = {"bits_k": 2.0, "bits_v": 1.5, "group_size": 64, "window": 32,
            "n_sink": 5, "fp8_meta": True}
     ctx.update(dims=dims, pol=pol,
